@@ -94,6 +94,11 @@ K = 4
 HORIZON_ROUNDS = 100
 HORIZON_N = 512
 
+# The step driver is timed with the projection its gates and the "step"
+# cost model (launch/analytic.py) were calibrated on; the drivers'
+# default is "mixed".
+STEP_BACKEND = "newton"
+
 FUSED_NS = (512, 4096, 32768)
 FUSED_GATE_N = 4096
 FUSED_REPS = 11
@@ -177,9 +182,10 @@ def run(json_path: str | None = None):
         t0 = time.perf_counter()
         ref = solve_pairs(beta[None, :], h2[0], cfg)
         t_np = time.perf_counter() - t0
-        solve_pairs_jit(beta[None, :], h2[0], cfg)      # warm the jit caches
+        # warm the jit caches
+        solve_pairs_jit(beta[None, :], h2[0], cfg, backend=STEP_BACKEND)
         t0 = time.perf_counter()
-        jit = solve_pairs_jit(beta[None, :], h2[0], cfg)
+        jit = solve_pairs_jit(beta[None, :], h2[0], cfg, backend=STEP_BACKEND)
         t_jit = time.perf_counter() - t0
         agree = _agreement(ref.time_s, jit, ref.feasible)
         rows.append([f"solve_pairs/np/N{n}", round(t_np * 1e6, 1), f"{K}x{n} pairs"])
@@ -193,9 +199,11 @@ def run(json_path: str | None = None):
     # ---- acceptance: whole-horizon Gamma precompute (always full scale) ---
     rounds = HORIZON_ROUNDS
     cfg, beta, h2_all = _setup(HORIZON_N, rounds)
-    solve_pairs_jit(beta[None, None, :], h2_all, cfg)        # warm/compile
+    solve_pairs_jit(beta[None, None, :], h2_all, cfg,
+                    backend=STEP_BACKEND)                    # warm/compile
     t0 = time.perf_counter()
-    jit = solve_pairs_jit(beta[None, None, :], h2_all, cfg)
+    jit = solve_pairs_jit(beta[None, None, :], h2_all, cfg,
+                          backend=STEP_BACKEND)
     t_jit = time.perf_counter() - t0
     t0 = time.perf_counter()
     ref_time = np.stack(
@@ -219,13 +227,15 @@ def run(json_path: str | None = None):
     record["polyblock_fused"] = {}
     for n in FUSED_NS:
         cfg, beta, h2 = _setup(n, 1, seed=3)
-        solve_pairs_jit(beta[None, :], h2[0], cfg)           # warm both jits
+        # warm both jits
+        solve_pairs_jit(beta[None, :], h2[0], cfg, backend=STEP_BACKEND)
         fused = solve_pairs_fused(beta[None, :], h2[0], cfg)
-        step = solve_pairs_jit(beta[None, :], h2[0], cfg)
+        step = solve_pairs_jit(beta[None, :], h2[0], cfg, backend=STEP_BACKEND)
         t_step, t_fused = [], []
         for _ in range(FUSED_REPS):                          # interleaved
             t0 = time.perf_counter()
-            step = solve_pairs_jit(beta[None, :], h2[0], cfg)
+            step = solve_pairs_jit(beta[None, :], h2[0], cfg,
+                                   backend=STEP_BACKEND)
             t_step.append(time.perf_counter() - t0)
             t0 = time.perf_counter()
             fused = solve_pairs_fused(beta[None, :], h2[0], cfg)
@@ -447,7 +457,8 @@ def run(json_path: str | None = None):
     emax0 = np.full((SCN_ROUNDS, SCN_N), wcfg.e_max_j)
     solve_pairs_jit(beta[None, None, :],
                     generate_traces(0, wcfg, "static", SCN_ROUNDS).h2_all,
-                    wcfg, emax0[:, None, :])                # warm/compile
+                    wcfg, emax0[:, None, :],
+                    backend=STEP_BACKEND)                   # warm/compile
     scn_rec = {}
     for name in ("static", "urban"):
         t_gen, t_solve = [], []
@@ -458,7 +469,8 @@ def run(json_path: str | None = None):
             t0 = time.perf_counter()
             ra = solve_pairs_jit(beta[None, None, :], tr.h2_all, wcfg,
                                  np.broadcast_to(tr.e_max_j[:, None, :],
-                                                 tr.h2_all.shape))
+                                                 tr.h2_all.shape),
+                                 backend=STEP_BACKEND)
             apply_dynamics(ra, tr.avail, tr.slowdown, beta, wcfg)
             t_solve.append(time.perf_counter() - t0)
         scn_rec[name] = {"trace_gen_s": min(t_gen), "solve_s": min(t_solve),

@@ -12,6 +12,9 @@ from __future__ import annotations
 
 import sys
 import time
+import traceback
+
+from repro.compile_cache import enable_compile_cache
 
 from . import (
     control_plane,
@@ -64,6 +67,7 @@ ALL = {
 
 
 def main() -> None:
+    enable_compile_cache()
     only = None
     if "--only" in sys.argv:
         only = sys.argv[sys.argv.index("--only") + 1]
@@ -79,14 +83,16 @@ def main() -> None:
         t = time.time()
         try:
             record = fn()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - report, run the rest, exit 1
+            traceback.print_exc()
             print(f"#table,{name}\nERROR,{type(e).__name__}: {e}")
+            failed.append(f"{name}: raised {type(e).__name__}")
         else:
             failed += [f"{name}: {g}" for g in _failed_gates(record)]
         print(f"# {name} took {time.time()-t:.1f}s\n")
     print(f"# total {time.time()-t0:.1f}s")
     if failed:
-        print("# GATE FAILURES:\n" + "\n".join(f"#   {g}" for g in failed))
+        print("# FAILURES:\n" + "\n".join(f"#   {g}" for g in failed))
         sys.exit(1)
 
 

@@ -38,4 +38,7 @@ def run(rounds=60, n_samples=500, seeds=(0, 1)):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

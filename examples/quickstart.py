@@ -60,5 +60,8 @@ def short_sim():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     one_round()
     short_sim()

@@ -31,13 +31,14 @@ iteration trajectory replays the host algorithm's structure exactly, so
     execute a row scatter as a serial loop).
 
 The projection (eqs. 27-29) dispatches through `kernels.polyblock_project`:
-warm-started safeguarded log-space Newton ("newton", default — same root as
-the reference 60-step bisection to ~1e-9 relative with 4x fewer
-transcendental evaluations), exact mirrored bisection ("bisect"), or the
-Pallas kernel ("pallas", default on TPU).  Everything runs float64 under a
-scoped `jax.experimental.enable_x64`, so results match the NumPy path to
-~1e-7 relative (1e-6 contract, tests/test_monotonic_jax.py) without
-enabling x64 globally for the learning plane.
+fp32-bulk/fp64-polish Newton ("mixed", the default on every platform),
+warm-started safeguarded log-space Newton ("newton" — same root as the
+reference 60-step bisection to ~1e-9 relative with 4x fewer transcendental
+evaluations on the CPU), exact mirrored bisection ("bisect"), or the
+float32 Pallas kernel ("pallas", opt-in).  Everything else runs float64 under a scoped
+`jax.enable_x64(True)`, so results match the NumPy path to ~1e-7 relative
+(1e-6 contract, tests/test_monotonic_jax.py) without enabling x64
+globally for the learning plane.
 
 At the acceptance scale (100 rounds x K=4 x N=512 on a 2-core CPU
 container) the whole-horizon solve is ~11x faster than the per-round host
@@ -52,7 +53,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 # The masked-select store rewrite intentionally produces fresh buffers for
 # the four (rows, m, ...) store arrays, so XLA cannot reuse their donated
@@ -288,15 +288,14 @@ def _fused_stage_sharded(state, *, cfg, backend, n_bisect, eps,
     per-shard early exit is safe because retired rows are frozen (the
     selection half-step is a no-op on a fully-retired shard), so results
     stay bit-identical to the unsharded path."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec
 
     mesh = Mesh(np.array(jax.local_devices()), ("rows",))
     spec = PartitionSpec("rows")
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda st: _fused_stage_impl(st, cfg, backend, n_bisect, eps,
                                      t_start, t_end),
-        mesh=mesh, in_specs=(spec,), out_specs=spec, check_rep=False)
+        mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False)
     return fn(state)
 
 
@@ -334,12 +333,13 @@ def solve_pairs_fused(
         iteration m - 3, since step t writes slot <= t + 1).  ~19 syncs
         become <= 6, and none happen where the batch is already narrow;
 
-      * transcendental volume — with backend "mixed" (the CPU default
-        here), the child projections run the fp32-bulk/fp64-polish Newton
-        (`kernels.polyblock_project.project_newton_mixed`): same safeguarded
-        loop, ~2x the SIMD width for the bracket contraction, fp64 polish
-        pinned to the f64 Newton root at ~1e-12 relative (the
-        fp32-accumulation study, DESIGN.md §13).
+      * transcendental volume — with backend "mixed" (the default on
+        every platform), the child projections run the fp32-bulk/
+        fp64-polish Newton (`kernels.polyblock_project.
+        project_newton_mixed`): same safeguarded loop, ~2x the SIMD width
+        for the bracket contraction, fp64 polish pinned to the f64 Newton
+        root at ~1e-12 relative (the fp32-accumulation study, DESIGN.md
+        §13).
 
     backend: as in `solve_pairs_jit`, plus "mixed", and "pallas" here means
     the *fully fused* single-kernel solve (`kernels.polyblock_fused`) —
@@ -360,7 +360,7 @@ def solve_pairs_fused(
     e_max = cfg.e_max_j if e_max is None else e_max
     eps = 0.01 if eps is None else float(eps)
     if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "mixed"
+        backend = "mixed"
     if backend == "jnp":
         backend = "bisect"
 
@@ -393,7 +393,9 @@ def solve_pairs_fused(
         from ..kernels.polyblock_fused.ops import polyblock_solve_fused
 
         interpret = jax.default_backend() != "tpu"
-        with enable_x64():
+        # Compiled, the kernel runs float32 and Mosaic refuses 64-bit
+        # scalars, so x64 is on only for the float64 interpret path.
+        with jax.enable_x64(interpret):
             k_tau, k_p, k_time, k_it = polyblock_solve_fused(
                 beta_f[work], h2f[work], e_f[work], cfg,
                 eps=eps, max_iter=max_iter, n_bisect=n_bisect,
@@ -437,7 +439,7 @@ def solve_pairs_fused(
                 break
             mm = min(3 * mm, m_full)
         bounds = sorted(set(bounds))
-        with enable_x64():
+        with jax.enable_x64(True):
             state = _init_state(
                 jnp.asarray(np.concatenate([beta_f[work], np.ones(pad)])),
                 jnp.asarray(np.concatenate([h2f[work], np.ones(pad)])),
@@ -507,8 +509,10 @@ def solve_pairs_jit(
     Drop-in for `monotonic.solve_pairs` (same arguments and RAResult contract,
     host numpy outputs); pass the whole-horizon (rounds x K x N) channel
     tensor to amortize a single solve over the training horizon.  backend:
-    None (auto: "pallas" on TPU else "newton"), "newton", "bisect" (exact
-    mirror of the host bisection), "jnp" (alias of "bisect"), or "pallas".
+    None or "mixed" (the default on every platform), "newton", "bisect"
+    (exact mirror of the host bisection), "jnp" (alias of "bisect"), or
+    "pallas" (float32, opt-in).  On a TPU v5e only "mixed" and "bisect"
+    hold the 1e-6 contract (ROADMAP S2).
     n_bisect sets the bisection step count of the "bisect"/"pallas"
     projections; the "newton" backend converges by a different rule and has
     its own fixed step budget (`project_newton`'s n_steps).
@@ -518,7 +522,7 @@ def solve_pairs_jit(
     e_max = cfg.e_max_j if e_max is None else e_max
     eps = 0.01 if eps is None else float(eps)
     if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "newton"
+        backend = "mixed"
     if backend == "jnp":
         backend = "bisect"
 
@@ -553,7 +557,7 @@ def solve_pairs_jit(
         b = _bucket(work.size)
         pad = b - work.size
         row_orig = np.concatenate([work, np.full(pad, -1, np.int64)])
-        with enable_x64():
+        with jax.enable_x64(True):
             state = _init_state(
                 jnp.asarray(np.concatenate([beta_f[work], np.ones(pad)])),
                 jnp.asarray(np.concatenate([h2f[work], np.ones(pad)])),

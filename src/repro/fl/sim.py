@@ -755,7 +755,6 @@ def _dispatch_group(run, datas: list[dict], shard: bool):
     if len(datas) == 1:
         ys = jax.jit(run)(datas[0])
     elif shard and n_dev > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec
 
         pad = (-len(datas)) % n_dev
@@ -763,10 +762,10 @@ def _dispatch_group(run, datas: list[dict], shard: bool):
             lambda *leaves: jnp.stack(leaves),
             *(list(datas) + [datas[0]] * pad))
         mesh = Mesh(np.asarray(jax.local_devices()), ("batch",))
-        sharded = shard_map(jax.vmap(run), mesh=mesh,
-                            in_specs=PartitionSpec("batch"),
-                            out_specs=PartitionSpec("batch"),
-                            check_rep=False)
+        sharded = jax.shard_map(jax.vmap(run), mesh=mesh,
+                                in_specs=PartitionSpec("batch"),
+                                out_specs=PartitionSpec("batch"),
+                                check_vma=False)
         ys = jax.jit(sharded)(stacked)
     else:
         stacked = jax.tree_util.tree_map(

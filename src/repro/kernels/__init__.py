@@ -9,6 +9,6 @@ pure-jnp oracle (ref.py) and a jitted wrapper (ops.py):
                        ref.py here is NumPy (it doubles as the host solver's
                        projection), the jnp oracle lives in ops.project_jnp
 
-On CPU the wrappers run interpret=True (kernel bodies execute in Python);
-on TPU they compile to Mosaic.
+On a TPU the wrappers compile the kernels to Mosaic.  Interpret mode
+(kernel bodies executed op by op on the CPU) is the CPU test path only.
 """

@@ -27,14 +27,17 @@ operation-for-operation —
   * every store write is masked by the active set, so retired lanes are
     frozen bit-exactly as in the phase-split driver.
 
-So in float64 (interpret mode off-TPU) the kernel is *bit-identical* to
-`solve_pairs_fused(backend="bisect")` including the per-pair iteration
-count; in float32 (TPU compiled) it is the fp32-accumulation study's
+So in float64 (interpret mode, the CPU test path) the kernel is
+*bit-identical* to `solve_pairs_fused(backend="bisect")` including the
+per-pair iteration count; in float32 it is the fp32-accumulation study's
 subject: pairs whose eq.-26 retirement is decided clear of fp32 noise
 (all but ~1% of a random batch) keep the f64 trajectory exactly and land
 at <= 1e-4 relative, and a boundary pair (|Δf| within fp32 noise of
 eps = 0.01) may retire one iteration early/late but stays within the
-retirement tolerance itself, |T - T_f64| <= eps (DESIGN.md §13).
+retirement tolerance itself, |T - T_f64| <= eps (DESIGN.md §13).  That
+holds with IEEE float32 arithmetic; compiled for a v5e, whose float32
+log is good to ~3e-4 relative, the kernel misses it (ROADMAP S2), which
+is why no driver takes it by default.
 
 Layout: the vertex store needs max_iter + 1 slots (iteration t writes
 child2 into slot t + 1), carried as five (m, bm, 128) arrays — at the
@@ -107,22 +110,27 @@ def _solve_kernel(beta_ref, h2_ref, emax_ref,
 
     # Vertex store: five (m, bm, 128) carries.  fval == -inf marks an
     # unwritten slot (the jnp driver's `valid` mask): written slots always
-    # carry a finite f, since tau, p >= TINY * TINY keeps T finite.
-    verts_tau = jnp.zeros((m,) + shape, dt).at[0].set(one)
-    verts_p = jnp.zeros((m,) + shape, dt).at[0].set(one)
-    proj_tau = jnp.zeros((m,) + shape, dt).at[0].set(pj0_tau)
-    proj_p = jnp.zeros((m,) + shape, dt).at[0].set(pj0_p)
-    vfval = jnp.full((m,) + shape, neg_inf, dt).at[0].set(f0)
-
+    # carry a finite f, since tau, p >= TINY * TINY keeps T finite.  Slot 0
+    # is written by a select over the slot iota: Mosaic has no scatter.
     slot = jax.lax.broadcasted_iota(jnp.int32, (m,) + shape, 0)
+    first = slot == 0
+    zeros = jnp.zeros((m,) + shape, dt)
+    verts_tau = jnp.where(first, one[None], zeros)
+    verts_p = jnp.where(first, one[None], zeros)
+    proj_tau = jnp.where(first, pj0_tau[None], zeros)
+    proj_p = jnp.where(first, pj0_p[None], zeros)
+    vfval = jnp.where(first, f0[None], neg_inf)
 
+    # The active mask is carried as int32: Mosaic cannot carry a bool
+    # vector through a loop (it fails to legalize the scf.yield).
     def cond(carry):
         t, *_, active, _pb, _bf, _bt, _bp, _it, _nv = carry
-        return (t < max_iter) & active.any()
+        return (t < max_iter) & (jnp.max(active) > 0)
 
     def body(carry):
         (t, verts_tau, verts_p, proj_tau, proj_p, vfval,
-         active, prev_best, best_f, best_tau, best_p, iters, nvalid) = carry
+         active_i, prev_best, best_f, best_tau, best_p, iters, nvalid) = carry
+        active = active_i > 0
 
         # Selection half-step (paper steps 9-10).  First-max tie-break as
         # a min-index reduction over the slot axis.
@@ -167,10 +175,11 @@ def _solve_kernel(beta_ref, h2_ref, emax_ref,
         nvalid = nvalid + active.astype(jnp.int32)
 
         return (t + 1, verts_tau, verts_p, proj_tau, proj_p, vfval,
-                active, prev_best, best_f, best_tau, best_p, iters, nvalid)
+                active.astype(jnp.int32), prev_best, best_f, best_tau,
+                best_p, iters, nvalid)
 
     carry = (jnp.int32(0), verts_tau, verts_p, proj_tau, proj_p, vfval,
-             jnp.ones(shape, bool), jnp.full(shape, jnp.inf, dt),
+             jnp.ones(shape, jnp.int32), jnp.full(shape, jnp.inf, dt),
              f0, pj0_tau, pj0_p,
              jnp.zeros(shape, jnp.int32), jnp.ones(shape, jnp.int32))
     carry = jax.lax.while_loop(cond, body, carry)
@@ -197,7 +206,7 @@ def polyblock_solve_call(beta, h2, e_max, *, eps: float, max_iter: int,
         kappa0_mu=kappa0_mu, mu_cycles=mu_cycles, cpu_hz=cpu_hz, pt_w=pt_w,
         model_bits=model_bits, bandwidth_hz=bandwidth_hz,
     )
-    spec = pl.BlockSpec((bm, 128), lambda i: (i, 0))
+    spec = pl.BlockSpec((bm, 128), lambda i: (i, jnp.int32(0)))
     return pl.pallas_call(
         kern,
         grid=(rows // bm,),

@@ -33,7 +33,9 @@ def polyblock_solve_fused(beta, h2, e_max, cfg: WirelessConfig, *,
     Returns (tau, p, time_s, iterations) as flat arrays of the input
     length; dtype defaults to float64 in interpret mode (bit-identical to
     the jnp `backend="bisect"` solver) and float32 compiled on TPU (the
-    fp32-accumulation study, <= 1e-4 relative — DESIGN.md §13).
+    fp32-accumulation study, <= 1e-4 relative on the CPU — DESIGN.md §13;
+    compiled on a v5e it measured up to ~7e-2, ROADMAP S2).
+    Interpret mode is the CPU test path only; on a TPU the kernel compiles.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

@@ -77,7 +77,7 @@ def polyblock_project_call(tau_v, p_v, beta, h2, e_max, *, n_bisect: int = 60,
         cpu_hz=cpu_hz, pt_w=pt_w, model_bits=model_bits,
         bandwidth_hz=bandwidth_hz,
     )
-    spec = pl.BlockSpec((bm, 128), lambda i: (i, 0))
+    spec = pl.BlockSpec((bm, 128), lambda i: (i, jnp.int32(0)))
     return pl.pallas_call(
         kern,
         grid=(rows // bm,),

@@ -1,12 +1,12 @@
 """Dispatcher for the fused polyblock projection.
 
-Four interchangeable backends (tests assert pairwise agreement):
+Five interchangeable backends (tests assert pairwise agreement):
 
   "ref"    — host NumPy bisection (`ref.py`), float64; also what the legacy
              `core.monotonic._project` runs.
   "bisect" — fused jax.numpy `lax.fori_loop` mirror of "ref" (same
              arithmetic in the same order), jit/vmap-safe; float64 under an
-             `jax.experimental.enable_x64` scope.  Alias: "jnp".
+             `jax.enable_x64(True)` scope.  Alias: "jnp".
   "newton" — safeguarded Newton-bisection hybrid: each step evaluates g of
              eq. (22) ONCE (the expensive log1p is shared between g and g'),
              takes the Newton step when it stays inside the current bracket
@@ -14,9 +14,15 @@ Four interchangeable backends (tests assert pairwise agreement):
              convergence reaches the float64 root in ~8 engaged steps, so
              `n_steps` = 16 replaces the reference's 60 bisections (~4x
              fewer constraint evaluations) while agreeing with it to
-             ~1e-12 in zeta — this is the jitted solver's CPU default.
+             ~1e-12 in zeta on the CPU.  On a TPU v5e (emulated float64)
+             it measured up to 4.25e-1 relative off NumPy `core.monotonic`,
+             so it is opt-in there (ROADMAP S2).
+  "mixed"  — "newton" with the bracket contraction in float32 and a
+             float64 polish (`project_newton_mixed`): the default of both
+             Γ drivers on every platform.
   "pallas" — the VMEM-resident kernel (`kernel.py`), float32 (TPU has no
-             f64). Default on TPU; interpret-mode elsewhere.
+             f64).  Opt-in: compiled on a TPU, interpret mode (the CPU
+             test path) elsewhere.
 
 `project_jnp` / `project_newton` are exported separately because
 `core.monotonic_jax` embeds them inside its jitted solver steps.
@@ -300,12 +306,12 @@ def polyblock_project(v, beta, h2, e_max, cfg: WirelessConfig, *,
                       interpret: bool | None = None):
     """Project a batch of vertices.
 
-    backend: None (auto: "pallas" on TPU else "newton"), "ref", "bisect"
-    (alias "jnp"), "newton", "mixed" (fp32-bulk/fp64-polish Newton, the
-    fused solver's CPU default), or "pallas".
+    backend: None or "mixed" (fp32-bulk/fp64-polish Newton, the default
+    on every platform), "ref", "bisect" (alias "jnp"), "newton", or
+    "pallas".
     """
     if backend is None:
-        backend = "pallas" if _on_tpu() else "newton"
+        backend = "mixed"
     if backend == "ref":
         return project_ref(v, beta, h2, e_max, cfg, n_bisect=n_bisect)
     if backend in ("bisect", "jnp"):

@@ -39,8 +39,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
         u = u_ref[0].astype(jnp.float32)
         kv = k[:, None] * v[None, :]          # (hs, hs)
         y = ((state[...] + u[:, None] * kv) * r[:, None]).sum(axis=0)
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 y[None, None].astype(y_ref.dtype))
+        y_ref[pl.ds(0, 1), pl.ds(t, 1), :] = y[None, None].astype(y_ref.dtype)
         state[...] = w[:, None] * state[...] + kv
         return 0
 

@@ -14,10 +14,18 @@ import jax
 __all__ = ["make_production_mesh", "dp_axes_of", "smoke_mesh"]
 
 
+def _auto_mesh(shape, axes):
+    """`jax.make_mesh` with Auto axes: the model code places activations
+    with `with_sharding_constraint` and leaves the rest to GSPMD, which
+    Explicit axes (make_mesh's default) do not allow."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def dp_axes_of(mesh) -> tuple:
@@ -27,4 +35,4 @@ def dp_axes_of(mesh) -> tuple:
 
 def smoke_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many devices the test host has."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

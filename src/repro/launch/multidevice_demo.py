@@ -27,7 +27,7 @@ from ..models.transformer import init_params, param_count
 from ..sharding.partition import batch_shardings, param_shardings, opt_state_shardings
 from ..train.optimizer import make_optimizer
 from ..train.train_step import make_train_step
-from .mesh import dp_axes_of
+from .mesh import dp_axes_of, smoke_mesh
 from .train import fl_round_weights
 
 
@@ -37,7 +37,7 @@ def run(arch: str = "granite-moe-3b-a800m-smoke", steps: int = 8,
     assert jax.device_count() >= data * model, (
         f"need {data*model} devices, have {jax.device_count()} "
         "(set XLA_FLAGS=--xla_force_host_platform_device_count=8)")
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = smoke_mesh(data, model)
     ctx = ShardCtx(mesh=mesh, dp_axes=("data",))
     cfg = get_config(arch)
 

@@ -115,7 +115,6 @@ def sharded_causal_attention(qg, k, v, scale, window: int, chunk: int, ctx):
 
     qg: (B, S, Hkv, G, Dh); k, v: (B, S, Hkv, Dh*).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = ctx.mesh
@@ -129,13 +128,13 @@ def sharded_causal_attention(qg, k, v, scale, window: int, chunk: int, ctx):
         def fn(q_l, k_l, v_l):
             return _full_attn(q_l, k_l, v_l, scale, window, chunk)
 
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(b_ax, None, "model", None, None),
                       P(b_ax, None, "model", None),
                       P(b_ax, None, "model", None)),
             out_specs=P(b_ax, None, "model", None, None),
-            check_rep=False,
+            check_vma=False,
         )(qg, k, v)
 
     if s % mp == 0:
@@ -147,13 +146,13 @@ def sharded_causal_attention(qg, k, v, scale, window: int, chunk: int, ctx):
             return _full_attn(q_l, k_f, v_f, scale, window,
                               min(chunk, s_loc) if chunk else 0, off)
 
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(b_ax, "model", None, None, None),
                       P(b_ax, None, None, None),
                       P(b_ax, None, None, None)),
             out_specs=P(b_ax, "model", None, None, None),
-            check_rep=False,
+            check_vma=False,
         )(qg, k, v)
 
     # Fallback: GSPMD auto.

@@ -32,7 +32,6 @@ import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..configs.base import ArchConfig
 from .layers import DTYPE, dense_init, swiglu, swiglu_init
@@ -181,7 +180,7 @@ def moe_apply(p, cfg: ArchConfig, x, ctx: ShardCtx):
             aux_l = jax.lax.pmean(aux_l, ctx.ep_axis)
             return y_l, aux_l
 
-        y2d, aux = shard_map(
+        y2d, aux = jax.shard_map(
             shard_fn,
             mesh=ctx.mesh,
             in_specs=(
@@ -192,7 +191,7 @@ def moe_apply(p, cfg: ArchConfig, x, ctx: ShardCtx):
                 P(ctx.ep_axis, None, None),
             ),
             out_specs=(tok_spec, P()),
-            check_rep=False,
+            check_vma=False,
         )(x2d, p["router"]["w"], p["gate"], p["up"], p["down"])
         # Name the combined output so the remat policy can SAVE it: without
         # this, rematerialization re-executes the psum in the backward pass,

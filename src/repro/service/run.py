@@ -24,13 +24,14 @@ import argparse
 import dataclasses
 import json
 
+from ..compile_cache import enable_compile_cache
 from ..core import RoundPolicy
 from ..experiments.figures import render_service_gallery
 from ..experiments.store import next_version_dir, write_record
 from ..fl.sim import SimConfig
 from .harness import ServiceConfig, SustainedService
 
-__all__ = ["build_service_config", "main"]
+__all__ = ["build_service_config", "parse_args", "main"]
 
 # One tiny deployment every environment can replay in ~a minute: the CI
 # `service-smoke` job runs exactly this preset and uploads the artifact.
@@ -106,7 +107,7 @@ def _parser() -> argparse.ArgumentParser:
     return p
 
 
-def main(argv: list[str] | None = None) -> int:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     p = _parser()
     args = p.parse_args(argv)
     if args.smoke:
@@ -115,7 +116,12 @@ def main(argv: list[str] | None = None) -> int:
         for flag, value in SMOKE.items():
             if getattr(args, flag) == defaults[flag]:
                 setattr(args, flag, value)
+    return args
 
+
+def main(argv: list[str] | None = None) -> int:
+    args = parse_args(argv)
+    enable_compile_cache()
     cfg = build_service_config(args)
     sim = cfg.sim
     print(f"[service] {sim.dataset} N={sim.n_devices} K={sim.n_subchannels} "

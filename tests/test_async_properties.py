@@ -237,24 +237,28 @@ def test_aggregation_spec_resolution():
 def test_aggregate_buffered_endpoints():
     """server_lr == 1 must be bitwise eq.-34; an empty commit must be
     bitwise identity; intermediate step sizes land strictly between."""
+    import jax
     import jax.numpy as jnp
 
     from repro.fl import aggregate
 
+    # Both sides compiled, as in the engines: eager op-by-op dispatch may
+    # sum the weighted mean in a different order than the fused program.
+    aggregate_buffered_jit = jax.jit(aggregate_buffered)
     rng = np.random.default_rng(3)
     g = {"w": jnp.asarray(rng.normal(size=(4, 3)), jnp.float32)}
     c = {"w": jnp.asarray(rng.normal(size=(5, 4, 3)), jnp.float32)}
     w = jnp.asarray([1.0, 0.0, 2.0, 0.5, 0.0], jnp.float32)
     sync = aggregate(g, c, w)
-    full_step = aggregate_buffered(g, c, w, jnp.float32(1.0))
+    full_step = aggregate_buffered_jit(g, c, w, jnp.float32(1.0))
     np.testing.assert_array_equal(np.asarray(sync["w"]),
                                   np.asarray(full_step["w"]))
-    nothing_committed = aggregate_buffered(g, c, jnp.zeros(5, jnp.float32),
-                                           jnp.float32(1.0))
+    nothing_committed = aggregate_buffered_jit(
+        g, c, jnp.zeros(5, jnp.float32), jnp.float32(1.0))
     np.testing.assert_array_equal(np.asarray(g["w"]),
                                   np.asarray(nothing_committed["w"]))
     # Strictly between the endpoints the commit moves the model partway.
-    mixed = aggregate_buffered(g, c, w, jnp.float32(0.4))
+    mixed = aggregate_buffered_jit(g, c, w, jnp.float32(0.4))
     assert not np.array_equal(np.asarray(mixed["w"]), np.asarray(g["w"]))
     assert not np.array_equal(np.asarray(mixed["w"]), np.asarray(sync["w"]))
     with pytest.raises(ValueError):

@@ -3,7 +3,7 @@
 (`kernels.polyblock_fused`) against the step driver (`solve_pairs_jit`).
 
 Set REPRO_DIFF_BACKEND=pallas to run the driver grid with the single-kernel
-solve (interpret mode off-TPU) — the CI differential job does exactly that,
+solve (interpret mode, the CPU test path) — the CI differential job does exactly that,
 mirroring tests/test_scan_equivalence.py.
 """
 import os
@@ -119,8 +119,7 @@ def test_fused_pallas_kernel_f32_study():
     iteration trajectory of the f64 solve and lands within 1e-4 relative
     (this batch has no eps-boundary retirements; that case is pinned in
     test_kernels.py::test_polyblock_fused_solve_interpret_vs_oracle)."""
-    pytest.importorskip("jax")
-    from jax.experimental import enable_x64
+    jax = pytest.importorskip("jax")
 
     from repro.kernels.polyblock_fused.ops import polyblock_solve_fused
 
@@ -129,7 +128,7 @@ def test_fused_pallas_kernel_f32_study():
     keep = ~is_infeasible(hf, CFG, np.full(hf.size, CFG.e_max_j))
     bf, hf = bf[keep], hf[keep]
     assert keep.any()
-    with enable_x64():
+    with jax.enable_x64(True):
         t64, p64, s64, i64 = polyblock_solve_fused(
             bf, hf, CFG.e_max_j, CFG, interpret=True, dtype=np.float64)
     t32, p32, s32, i32 = polyblock_solve_fused(

@@ -264,7 +264,7 @@ for a, b in zip(sh, un):
     assert np.array_equal(a.latency_all, b.latency_all)
 print("HIER_SHARD_OK")
 """
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
                           " --xla_force_host_platform_device_count=2"),
                PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH", ""))

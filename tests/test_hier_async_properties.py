@@ -23,6 +23,10 @@ from repro.fl.server import AsyncAggregation, staleness_weight
 from repro.scenarios import FadingProcess, sample_coupled_fading, \
     sample_fading
 
+# Smallest drawn remaining time: 1e-3 rounded to float32, since a width-32
+# float strategy takes only bounds that float32 represents exactly.
+_F32_MS = float(np.float32(1e-3))
+
 # --------------------------------------------------------------------------
 # staleness weights: exact fresh-commit identity + normalization
 # --------------------------------------------------------------------------
@@ -72,7 +76,7 @@ def test_global_commit_bounded_by_cell_count_buffer(data, n_cells):
     is the exact remaining time of some in-flight cell (or 0 when the
     sky is empty)."""
     rem = np.asarray(data.draw(st.lists(
-        st.floats(min_value=1e-3, max_value=1e3, allow_nan=False,
+        st.floats(min_value=_F32_MS, max_value=1e3, allow_nan=False,
                   allow_infinity=False, width=32),
         min_size=n_cells, max_size=n_cells)), np.float32)
     active = np.asarray(data.draw(st.lists(
@@ -113,7 +117,7 @@ def test_virtual_clocks_non_decreasing_any_trace(data):
         dispatch = np.asarray(data.draw(st.lists(
             st.booleans(), min_size=n, max_size=n))) & free
         times = np.asarray(data.draw(st.lists(
-            st.floats(min_value=1e-3, max_value=1e3, allow_nan=False,
+            st.floats(min_value=_F32_MS, max_value=1e3, allow_nan=False,
                       allow_infinity=False, width=32),
             min_size=n, max_size=n)), np.float32)
         active = active | dispatch

@@ -144,7 +144,8 @@ def test_dryrun_subprocess_one_combo():
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "whisper-base", "--shape", "decode_32k"],
-        env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src")},
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": os.path.join(REPO, "src")},
         capture_output=True, text=True, timeout=900,
     )
     assert out.returncode == 0, out.stdout + out.stderr
@@ -157,7 +158,8 @@ def test_multidevice_execution_subprocess():
     8-device host mesh: loss must decrease."""
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.multidevice_demo", "--steps", "4"],
-        env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src")},
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": os.path.join(REPO, "src")},
         capture_output=True, text=True, timeout=900,
     )
     assert out.returncode == 0, out.stdout + out.stderr
@@ -205,7 +207,7 @@ for field in ("feasible", "iterations", "tau", "p", "time_s", "energy_j"):
                           equal_nan=True), field
 print("GAMMA_SHARD_OK")
 """
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
                           " --xla_force_host_platform_device_count=2"),
                PYTHONPATH=os.path.join(REPO, "src") + os.pathsep +

@@ -223,7 +223,7 @@ for a, b in zip(sh, un):
     assert np.array_equal(a.global_loss, b.global_loss)
 print("SHARD_OK")
 """
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
                           " --xla_force_host_platform_device_count=2"),
                PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH", ""))
