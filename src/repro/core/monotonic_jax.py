@@ -61,6 +61,7 @@ import numpy as np
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 
+from .. import tracing
 from ..kernels.polyblock_project.ops import (polyblock_project,
                                              project_newton_mixed)
 from .feasibility import is_infeasible
@@ -448,15 +449,17 @@ def solve_pairs_fused(
                 cfg=cfg, m=m, backend=backend, n_bisect=n_bisect)
             t = 0
             for t_end in bounds:
-                while m - 2 < t_end and m < m_full:  # widen the store first
-                    new_m = min(max(m + (m >> 1), t_end + 2), m_full)
-                    state = _grow(state, new_m=new_m)
-                    m = new_m
-                state = stage(state, cfg=cfg, backend=backend,
-                              n_bisect=n_bisect, eps=eps,
-                              t_start=t, t_end=t_end)
-                t = t_end
-                act = np.asarray(state[_ACTIVE])
+                with tracing.span("gamma.stage", rows=b, t_end=t_end):
+                    while m - 2 < t_end and m < m_full:  # widen the store
+                        new_m = min(max(m + (m >> 1), t_end + 2), m_full)
+                        state = _grow(state, new_m=new_m)
+                        m = new_m
+                    state = stage(state, cfg=cfg, backend=backend,
+                                  n_bisect=n_bisect, eps=eps,
+                                  t_start=t, t_end=t_end)
+                    t = t_end
+                    act = np.asarray(state[_ACTIVE])
+                    tracing.count("gamma.host_syncs")
                 na = int(act.sum())
                 if na == 0 or t >= max_iter:
                     break
@@ -470,6 +473,7 @@ def solve_pairs_fused(
                     bp, bf, it = (np.asarray(state[_BESTP]),
                                   np.asarray(state[_BESTF]),
                                   np.asarray(state[_ITERS]))
+                    tracing.count("gamma.host_syncs", 3)
                     flush(~act, row_orig, bp, bf, it)
                     keep = np.where(act)[0]
                     idx = np.concatenate(
@@ -481,6 +485,7 @@ def solve_pairs_fused(
             bp, bf, it = (np.asarray(state[_BESTP]),
                           np.asarray(state[_BESTF]),
                           np.asarray(state[_ITERS]))
+            tracing.count("gamma.host_syncs", 3)
             flush(np.ones(b, bool), row_orig, bp, bf, it)
 
     return RAResult(

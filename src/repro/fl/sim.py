@@ -103,6 +103,7 @@ from ..data.fl_datasets import (
     partition_dirichlet,
     partition_imbalanced_iid,
 )
+from .. import tracing
 from ..models.small import SmallModel, get_small_model
 from ..train.optimizer import make_optimizer
 from .async_loop import build_async_runner
@@ -284,41 +285,47 @@ def _prepare(cfg: SimConfig, _data_cache: dict | None = None) -> _Prepared:
     consults the scenario, so the cache stores the generator state at the
     branch point and replaying it is bit-identical to resampling.
     """
-    rng = np.random.default_rng(cfg.seed)
-    wcfg = cfg.wireless()
-    scn = get_scenario(cfg.scenario)
+    with tracing.span("sim.prepare"):
+        rng = np.random.default_rng(cfg.seed)
+        wcfg = cfg.wireless()
+        scn = get_scenario(cfg.scenario)
 
-    data_key = (cfg.dataset, cfg.n_samples, cfg.partition,
-                cfg.dirichlet_alpha, cfg.n_devices, cfg.seed)
-    if _data_cache is not None and data_key in _data_cache:
-        ds, part, beta, x_all, y_all, m_all, state = _data_cache[data_key]
-        rng.bit_generator.state = state
-    else:
-        ds, part, beta, x_all, y_all, m_all = _sample_dataset(cfg, rng)
-        if _data_cache is not None:
-            _data_cache[data_key] = (ds, part, beta, x_all, y_all, m_all,
-                                     rng.bit_generator.state)
+        data_key = (cfg.dataset, cfg.n_samples, cfg.partition,
+                    cfg.dirichlet_alpha, cfg.n_devices, cfg.seed)
+        with tracing.span("prepare.dataset"):
+            if _data_cache is not None and data_key in _data_cache:
+                (ds, part, beta, x_all, y_all, m_all,
+                 state) = _data_cache[data_key]
+                rng.bit_generator.state = state
+            else:
+                (ds, part, beta, x_all, y_all,
+                 m_all) = _sample_dataset(cfg, rng)
+                if _data_cache is not None:
+                    _data_cache[data_key] = (ds, part, beta, x_all, y_all,
+                                             m_all, rng.bit_generator.state)
 
-    distances = sample_distances(rng, wcfg, scn.mobility, cfg.rounds)
-    clusters = make_clusters(cfg.n_devices, cfg.n_subchannels, rng)
-    fixed_ids = rng.permutation(cfg.n_devices)[: cfg.n_subchannels]
-    g2_all = sample_fading(rng, wcfg, scn.fading, cfg.rounds)
-    h2_all = compose_gains(g2_all, distances, wcfg)
-    # One randomness stream for BOTH engines (DESIGN.md §8): every round's
-    # leader-plane permutations are drawn here, never inside the loop.
-    sel_perms = np.stack([rng.permutation(cfg.n_devices)
-                          for _ in range(cfg.rounds)])
-    assign_perms = np.stack([rng.permutation(cfg.n_subchannels)
-                             for _ in range(cfg.rounds)])
-    avail, slowdown = sample_churn(rng, scn.churn, cfg.rounds, cfg.n_devices)
-    emax_all = sample_energy(rng, wcfg, scn.energy, cfg.rounds)
+        distances = sample_distances(rng, wcfg, scn.mobility, cfg.rounds)
+        clusters = make_clusters(cfg.n_devices, cfg.n_subchannels, rng)
+        fixed_ids = rng.permutation(cfg.n_devices)[: cfg.n_subchannels]
+        g2_all = sample_fading(rng, wcfg, scn.fading, cfg.rounds)
+        h2_all = compose_gains(g2_all, distances, wcfg)
+        # One randomness stream for BOTH engines (DESIGN.md §8): every
+        # round's leader-plane permutations are drawn here, never inside
+        # the loop.
+        sel_perms = np.stack([rng.permutation(cfg.n_devices)
+                              for _ in range(cfg.rounds)])
+        assign_perms = np.stack([rng.permutation(cfg.n_subchannels)
+                                 for _ in range(cfg.rounds)])
+        avail, slowdown = sample_churn(rng, scn.churn, cfg.rounds,
+                                       cfg.n_devices)
+        emax_all = sample_energy(rng, wcfg, scn.energy, cfg.rounds)
 
-    return _Prepared(cfg=cfg, wcfg=wcfg, rng=rng, ds=ds, part=part, beta=beta,
-                     x_all=x_all, y_all=y_all, m_all=m_all, h2_all=h2_all,
-                     clusters=clusters, fixed_ids=fixed_ids,
-                     sel_perms=sel_perms, assign_perms=assign_perms,
-                     distances=distances, avail=avail, slowdown=slowdown,
-                     emax_all=emax_all)
+        return _Prepared(cfg=cfg, wcfg=wcfg, rng=rng, ds=ds, part=part,
+                         beta=beta, x_all=x_all, y_all=y_all, m_all=m_all,
+                         h2_all=h2_all, clusters=clusters, fixed_ids=fixed_ids,
+                         sel_perms=sel_perms, assign_perms=assign_perms,
+                         distances=distances, avail=avail, slowdown=slowdown,
+                         emax_all=emax_all)
 
 
 def _solve_horizons(
@@ -384,16 +391,16 @@ def _solve_horizons(
             np.broadcast_to(preps[i].emax_all[:, None, :],
                             preps[i].h2_all.shape).reshape(-1)
             for i in mo])
-        t0 = time.time()
-        if solver == "fused":
-            ra_flat = solve_pairs_fused(beta_cat, h2_cat, preps[mo[0]].wcfg,
-                                        emax_cat, backend=backend,
-                                        shard=shard)
-        else:
-            ra_flat = solve_pairs_jit(beta_cat, h2_cat, preps[mo[0]].wcfg,
-                                      emax_cat, backend=backend)
-        group_s = time.time() - t0
         group_pairs = h2_cat.size
+        with tracing.timed("gamma.solve", pairs=group_pairs) as clock:
+            if solver == "fused":
+                ra_flat = solve_pairs_fused(beta_cat, h2_cat,
+                                            preps[mo[0]].wcfg, emax_cat,
+                                            backend=backend, shard=shard)
+            else:
+                ra_flat = solve_pairs_jit(beta_cat, h2_cat, preps[mo[0]].wcfg,
+                                          emax_cat, backend=backend)
+        group_s = clock.seconds
         off = 0
         for i in mo:
             shp = preps[i].h2_all.shape
@@ -412,11 +419,11 @@ def _solve_horizons(
 
     for i, p in enumerate(preps):
         if out[i] is None and dup_of[i] is None:
-            t0 = time.time()
+            t0 = time.perf_counter()
             out[i] = fixed_ra(p.beta[None, None, :], p.h2_all, p.wcfg,
                               np.broadcast_to(p.emax_all[:, None, :],
                                               p.h2_all.shape))
-            secs[i] = time.time() - t0
+            secs[i] = time.perf_counter() - t0
     for i, rep in enumerate(dup_of):
         if rep is not None:
             out[i] = out[rep]
@@ -435,7 +442,7 @@ def _slice_ra(ra: RAResult, t: int) -> RAResult:
 
 def _run_prepared(prep: _Prepared, ra_all: RAResult, plan_wall_s: float) -> SimHistory:
     cfg, wcfg, rng, beta = prep.cfg, prep.wcfg, prep.rng, prep.beta
-    t_start = time.time()
+    t_start = time.perf_counter()
     t1 = TABLE1[cfg.dataset]
 
     # ---- model + trainer --------------------------------------------------
@@ -525,7 +532,7 @@ def _run_prepared(prep: _Prepared, ra_all: RAResult, plan_wall_s: float) -> SimH
         deficits=np.asarray(hist["deficit"]),
         grad_sq_norms=np.asarray(hist["gnorm"]),
         beta=beta,
-        wall_s=time.time() - t_start + plan_wall_s,
+        wall_s=time.perf_counter() - t_start + plan_wall_s,
         plan_wall_s=plan_wall_s,
         latency_all=lat_all,
         energy_all=energy_all,
@@ -750,28 +757,34 @@ def _dispatch_group(run, datas: list[dict], shard: bool):
     """Dispatch one static-shape group: solo jit, jit(vmap), or — with
     more than one visible local device — `shard_map` over a 1-D batch
     mesh (padded to a device-count multiple by repeating cell 0; pad rows
-    are dropped by the caller).  Returns the blocked-on ys."""
-    n_dev = jax.local_device_count()
-    if len(datas) == 1:
-        ys = jax.jit(run)(datas[0])
-    elif shard and n_dev > 1:
-        from jax.sharding import Mesh, PartitionSpec
+    are dropped by the caller).  Returns the blocked-on ys.
 
-        pad = (-len(datas)) % n_dev
-        stacked = jax.tree_util.tree_map(
-            lambda *leaves: jnp.stack(leaves),
-            *(list(datas) + [datas[0]] * pad))
-        mesh = Mesh(np.asarray(jax.local_devices()), ("batch",))
-        sharded = jax.shard_map(jax.vmap(run), mesh=mesh,
-                                in_specs=PartitionSpec("batch"),
-                                out_specs=PartitionSpec("batch"),
-                                check_vma=False)
-        ys = jax.jit(sharded)(stacked)
-    else:
-        stacked = jax.tree_util.tree_map(
-            lambda *leaves: jnp.stack(leaves), *datas)
-        ys = jax.jit(jax.vmap(run))(stacked)
-    jax.block_until_ready(ys)
+    Recorded, the `engine.dispatch` span's `compile_s` splits the jitted
+    call's trace, lowering and compile-or-load by JAX's own events, and
+    `engine.run` is the wait for the results."""
+    n_dev = jax.local_device_count()
+    with tracing.span("engine.dispatch", cells=len(datas)):
+        if len(datas) == 1:
+            ys = jax.jit(run)(datas[0])
+        elif shard and n_dev > 1:
+            from jax.sharding import Mesh, PartitionSpec
+
+            pad = (-len(datas)) % n_dev
+            stacked = jax.tree_util.tree_map(
+                lambda *leaves: jnp.stack(leaves),
+                *(list(datas) + [datas[0]] * pad))
+            mesh = Mesh(np.asarray(jax.local_devices()), ("batch",))
+            sharded = jax.shard_map(jax.vmap(run), mesh=mesh,
+                                    in_specs=PartitionSpec("batch"),
+                                    out_specs=PartitionSpec("batch"),
+                                    check_vma=False)
+            ys = jax.jit(sharded)(stacked)
+        else:
+            stacked = jax.tree_util.tree_map(
+                lambda *leaves: jnp.stack(leaves), *datas)
+            ys = jax.jit(jax.vmap(run))(stacked)
+        with tracing.span("engine.run"):
+            jax.block_until_ready(ys)
     return ys
 
 
@@ -794,12 +807,12 @@ def _run_group_scan(cfgs: Sequence[SimConfig], preps: Sequence[_Prepared],
     run = _build_scan_runner(cfg, model, trainer, policies)
     _check_f32_priorities(preps)
 
-    t_start = time.time()
+    t_start = time.perf_counter()
     bmax = max(int(p.part.beta.max()) for p in preps)
     datas = [_scan_inputs(p, ra, bmax, i)
              for p, ra, i in zip(preps, ras, pol_idx)]
     ys = _dispatch_group(run, datas, shard)
-    wall_each = (time.time() - t_start) / len(datas)
+    wall_each = (time.perf_counter() - t_start) / len(datas)
 
     out = []
     for i, (c, p, w) in enumerate(zip(cfgs, preps, plan_walls)):
@@ -854,7 +867,7 @@ def _run_group_async(cfgs: Sequence[SimConfig], preps: Sequence[_Prepared],
         track_gradnorm=cfg.track_gradnorm)
     _check_f32_priorities(preps)
 
-    t_start = time.time()
+    t_start = time.perf_counter()
     bmax = max(int(p.part.beta.max()) for p in preps)
     datas = []
     for c, p, ra, i in zip(cfgs, preps, ras, pol_idx):
@@ -866,7 +879,7 @@ def _run_group_async(cfgs: Sequence[SimConfig], preps: Sequence[_Prepared],
         d["server_lr"] = jnp.float32(spec.server_lr)
         datas.append(d)
     ys = _dispatch_group(run, datas, shard)
-    wall_each = (time.time() - t_start) / len(datas)
+    wall_each = (time.perf_counter() - t_start) / len(datas)
 
     out = []
     for i, (c, p, w) in enumerate(zip(cfgs, preps, plan_walls)):
@@ -926,65 +939,66 @@ def run_many(cfgs: Sequence[SimConfig], *,
         raise ValueError(f"unknown engine: {engine}")
     if ra_solver not in ("fused", "step"):
         raise ValueError(f"unknown ra_solver: {ra_solver}")
-    if shard is None:
-        shard = jax.local_device_count() > 1
-    # Per-cell execution mode: an async aggregation spec overrides the
-    # requested sync engine (and validates eagerly, before any sampling).
-    modes = ["async" if engine == "async" or get_aggregation(c.aggregation)
-             is not None else engine for c in cfgs]
+    with tracing.span("sim.run_many", cells=len(cfgs)):
+        if shard is None:
+            shard = jax.local_device_count() > 1
+        # Per-cell execution mode: an async aggregation spec overrides the
+        # requested sync engine (and validates eagerly, before any sampling).
+        modes = ["async" if engine == "async" or get_aggregation(c.aggregation)
+                 is not None else engine for c in cfgs]
 
-    # One _Prepared world per policy-free config: policy-only variants
-    # share data/topology/channels by construction (and hence Γ, below).
-    # Scenario-only variants are distinct worlds but still share the
-    # dataset phase (dataset/partition/padded buffers) via `data_cache` —
-    # the rng prefix up to the partition draw is scenario-independent.
-    preps_by_key: dict[SimConfig, _Prepared] = {}
-    data_cache: dict = {}
-    preps: list[_Prepared] = []
-    for c in cfgs:
-        key = _prep_key(c)
-        if key not in preps_by_key:
-            preps_by_key[key] = _prepare(c, data_cache)
-        shared = preps_by_key[key]
-        preps.append(shared if shared.cfg == c
-                     else dataclasses.replace(shared, cfg=c))
+        # One _Prepared world per policy-free config: policy-only variants
+        # share data/topology/channels by construction (and hence Γ, below).
+        # Scenario-only variants are distinct worlds but still share the
+        # dataset phase (dataset/partition/padded buffers) via `data_cache` —
+        # the rng prefix up to the partition draw is scenario-independent.
+        preps_by_key: dict[SimConfig, _Prepared] = {}
+        data_cache: dict = {}
+        preps: list[_Prepared] = []
+        for c in cfgs:
+            key = _prep_key(c)
+            if key not in preps_by_key:
+                preps_by_key[key] = _prepare(c, data_cache)
+            shared = preps_by_key[key]
+            preps.append(shared if shared.cfg == c
+                         else dataclasses.replace(shared, cfg=c))
 
-    ras, plan_walls = _solve_horizons(preps, ra_backend,
-                                      solver=ra_solver, shard=shard)
-    # Scenario dynamics (DESIGN.md §11): churn availability knocks out
-    # Prop-1 feasibility, straggler slowdowns stretch the eq.-1 compute
-    # share of Γ — folded into the whole-horizon RAResult ONCE, before
-    # either engine runs, so loop and scan consume identical tensors.
-    # Γ-deduped sims alias one RAResult and one world, so the transform is
-    # applied per unique object and re-aliased.
-    transformed: dict[int, RAResult] = {}
-    for i, (p, ra) in enumerate(zip(preps, ras)):
-        if id(ra) not in transformed:
-            transformed[id(ra)] = apply_dynamics(
-                ra, p.avail, p.slowdown, p.beta, p.wcfg)
-        ras[i] = transformed[id(ra)]
-    out: list[SimHistory | None] = [None] * len(cfgs)
-    for i, mode in enumerate(modes):
-        if mode == "loop":
-            out[i] = _run_prepared(preps[i], ras[i], plan_walls[i])
+        ras, plan_walls = _solve_horizons(preps, ra_backend,
+                                          solver=ra_solver, shard=shard)
+        # Scenario dynamics (DESIGN.md §11): churn availability knocks out
+        # Prop-1 feasibility, straggler slowdowns stretch the eq.-1 compute
+        # share of Γ — folded into the whole-horizon RAResult ONCE, before
+        # either engine runs, so loop and scan consume identical tensors.
+        # Γ-deduped sims alias one RAResult and one world, so the transform is
+        # applied per unique object and re-aliased.
+        transformed: dict[int, RAResult] = {}
+        for i, (p, ra) in enumerate(zip(preps, ras)):
+            if id(ra) not in transformed:
+                transformed[id(ra)] = apply_dynamics(
+                    ra, p.avail, p.slowdown, p.beta, p.wcfg)
+            ras[i] = transformed[id(ra)]
+        out: list[SimHistory | None] = [None] * len(cfgs)
+        for i, mode in enumerate(modes):
+            if mode == "loop":
+                out[i] = _run_prepared(preps[i], ras[i], plan_walls[i])
 
-    # Sync-mode and async-mode cells never share a program (different scan
-    # carries), so group within each mode; inside a mode the aggregation
-    # spec is data (buffer / exponent operands), not program shape.
-    groups: dict[tuple[str, SimConfig], list[int]] = {}
-    for i, (c, mode) in enumerate(zip(cfgs, modes)):
-        if mode != "loop":
-            groups.setdefault((mode, _scan_group_key(c)), []).append(i)
-    for (mode, _), idx in groups.items():
-        run_group = _run_group_scan if mode == "scan" else _run_group_async
-        hists = run_group([cfgs[i] for i in idx],
-                          [preps[i] for i in idx],
-                          [ras[i] for i in idx],
-                          [plan_walls[i] for i in idx],
-                          shard=shard)
-        for i, h in zip(idx, hists):
-            out[i] = h
-    return out
+        # Sync-mode and async-mode cells never share a program (different scan
+        # carries), so group within each mode; inside a mode the aggregation
+        # spec is data (buffer / exponent operands), not program shape.
+        groups: dict[tuple[str, SimConfig], list[int]] = {}
+        for i, (c, mode) in enumerate(zip(cfgs, modes)):
+            if mode != "loop":
+                groups.setdefault((mode, _scan_group_key(c)), []).append(i)
+        for (mode, _), idx in groups.items():
+            run_group = _run_group_scan if mode == "scan" else _run_group_async
+            hists = run_group([cfgs[i] for i in idx],
+                              [preps[i] for i in idx],
+                              [ras[i] for i in idx],
+                              [plan_walls[i] for i in idx],
+                              shard=shard)
+            for i, h in zip(idx, hists):
+                out[i] = h
+        return out
 
 
 def run_simulation(cfg: SimConfig, *, ra_backend: str | None = None,
