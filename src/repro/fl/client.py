@@ -14,6 +14,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from ..train.optimizer import Optimizer, apply_updates
 
 __all__ = ["make_local_trainer"]
@@ -39,7 +40,10 @@ def make_local_trainer(
 
     jit=False returns the raw traceable function instead of a `jax.jit`
     wrapper — the scan engine (fl.sim) embeds it inside its fused round
-    loop, where an inner jit boundary would only add dispatch overhead.
+    loop.  One slot's training is a `jax.jit` of its own either way: the
+    K slots trace it once, and a trainer kept across calls
+    (`fl.sim._group_trainer_and_policies`) is not traced again by a
+    round loop that is.
     """
 
     def masked_loss(params, x, y, m):
@@ -50,7 +54,9 @@ def make_local_trainer(
             per = jax.vmap(lambda xi, yi: loss_fn(params, xi[None], yi[None]))(x, y)
         return (per * m).sum() / jnp.maximum(m.sum(), 1.0)
 
+    @jax.jit
     def one_client(params, x, y, mask, key):
+        tracing.count("engine.subprogram_traces")
         # Local steps UNROLLED (local_steps is small + static): XLA-CPU
         # executes a lax.scan of this body ~30x slower than the unrolled
         # form (measured; conv grads inside scan hit a slow path).

@@ -7,14 +7,20 @@ live here once, imported by `fl.sim._build_scan_runner` and
 `fl.async_loop.build_async_runner`, instead of being mirrored by hand.
 Everything here is pure tracing scaffolding over the `data` dict contract
 of `fl.sim._scan_inputs`; no dispatch or history logic.
+
+The leader step is called through `jax.jit`, so a runner built afresh for
+every call re-traces only its round loop's glue: each leader variant is
+traced once per process per (static arguments, shapes).
 """
 from __future__ import annotations
 
+import functools
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from ..core import leader_round
 
 __all__ = ["make_leader_branches", "run_leader", "train_clients",
@@ -33,7 +39,7 @@ def make_leader_branches(policies: Sequence[tuple[str, str]], data, *,
     def leader_branch(ds, sa):
         def branch(ops):
             age, feas, x = ops
-            return leader_round(
+            return _jitted(leader_round)(
                 age, data["beta"], x["gamma"], feas,
                 x["sel_perm"], x["assign_perm"], x["t"],
                 data["clusters"], data["fixed_ids"],
@@ -42,6 +48,22 @@ def make_leader_branches(policies: Sequence[tuple[str, str]], data, *,
         return branch
 
     return [leader_branch(ds, sa) for ds, sa in policies]
+
+
+@functools.cache
+def _jitted(fn):
+    """`fn` (a `leader_round`) under `jax.jit` with its policy and shape
+    arguments static.  Memoised on the function object, which the branches
+    resolve by this module's global name at each call, so one patched in
+    its place is jitted on its own.  Its Python body runs only when JAX
+    misses its trace cache: that is what `engine.subprogram_traces`
+    counts."""
+    @functools.wraps(fn)
+    def traced(*args, **kw):
+        tracing.count("engine.subprogram_traces")
+        return fn(*args, **kw)
+    return jax.jit(traced, static_argnames=(
+        "ds", "sa", "k", "n", "n_clusters", "max_rounds"))
 
 
 def run_leader(branches, policy_idx, age, feasible, x):

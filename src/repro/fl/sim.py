@@ -65,6 +65,7 @@ The declarative front-end over this path lives in `repro.experiments`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from typing import Any, Sequence
@@ -716,19 +717,33 @@ def _prep_key(cfg: SimConfig) -> SimConfig:
     return dataclasses.replace(cfg, policy=RoundPolicy(), aggregation="sync")
 
 
+@functools.lru_cache(maxsize=16)
+def _model_and_trainer(make_trainer, get_model, make_opt, dataset: str,
+                       optimizer: str, lr: float, batch: int,
+                       local_steps: int):
+    """The model and trainer of one set of training settings, kept for
+    the process so that the trainer's jitted client step keeps its trace
+    cache from call to call.  The arguments are everything the trainer
+    closes over, the factories included: one patched in their place
+    builds its own."""
+    model = get_model(dataset)
+    trainer = make_trainer(
+        model.loss, make_opt(optimizer, lr), batch_size=batch,
+        local_steps=local_steps, loss_per_example=model.loss_per_example,
+        jit=False)
+    return model, trainer
+
+
 def _group_trainer_and_policies(cfgs: Sequence[SimConfig]):
     """Shared scan/async group setup: model, un-jitted trainer (the group
     program jits around it), and the group's distinct (ds, sa) leader
     variants in first-appearance order with each cell's branch index."""
     cfg = cfgs[0]
     t1 = TABLE1[cfg.dataset]
-    model = get_small_model(cfg.dataset)
-    opt = make_optimizer(cfg.optimizer or t1["optimizer"], cfg.lr or t1["lr"])
-    trainer = make_local_trainer(
-        model.loss, opt, batch_size=cfg.batch or t1["batch"],
-        local_steps=cfg.local_steps, loss_per_example=model.loss_per_example,
-        jit=False,
-    )
+    model, trainer = _model_and_trainer(
+        make_local_trainer, get_small_model, make_optimizer, cfg.dataset,
+        cfg.optimizer or t1["optimizer"], cfg.lr or t1["lr"],
+        cfg.batch or t1["batch"], cfg.local_steps)
     policies: list[tuple[str, str]] = []
     pol_idx = []
     for c in cfgs:

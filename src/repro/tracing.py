@@ -3,7 +3,12 @@
 Spans and counters sit at the host boundaries of the planner's layers
 (`fl.sim.run_many`, `_prepare`, `_solve_horizons`, `_dispatch_group`,
 `core.monotonic_jax.solve_pairs_fused`), never inside a function JAX
-traces.  Recording is off by default: `span` then hands back one shared
+traces, with one exception: the counter `engine.subprogram_traces` sits in
+the Python bodies of the engine's jitted sub-programs (the leader step in
+`fl.engine_common`, one client's local training in `fl.client`).  Those
+bodies run only when JAX misses its trace cache, while the dispatch that
+traces them is the innermost open span, and the count adds nothing to the
+traced program.  Recording is off by default: `span` then hands back one shared
 `contextlib.nullcontext()` and `count` returns at once, with no clock
 read and no allocation of its own.
 

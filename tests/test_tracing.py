@@ -196,6 +196,33 @@ def test_run_many_span_tree(runs, branch):
     assert hists[0].plan_wall_s == gamma.seconds
 
 
+def test_subprogram_traces_counted_on_the_dispatch():
+    """`engine.subprogram_traces`, counted where JAX traces the engine's
+    jitted sub-programs: nothing recorded while the recorder is off; on,
+    one per leader variant and one for the client step, all on the
+    `engine.dispatch` that traced them."""
+    from repro.fl import engine_common, sim
+
+    def clear():
+        jax.clear_caches()
+        sim._model_and_trainer.cache_clear()
+        engine_common._jitted.cache_clear()
+
+    cfgs = [SimConfig(**TINY, policy=RoundPolicy(ds=d, ra="fix"))
+            for d in ("alg3", "random")]
+    clear()
+    run_many(cfgs, engine="scan")
+    assert tracing.spans() == []
+    clear()
+    tracing.enable()
+    run_many(cfgs, engine="scan")
+    tracing.disable()
+    holders = [s for s in tracing.spans()
+               if "engine.subprogram_traces" in s.counts]
+    assert [s.name for s in holders] == ["engine.dispatch"]
+    assert holders[0].counts == {"engine.subprogram_traces": 3}
+
+
 def test_hierarchy_shares_the_engine_and_gamma_spans():
     """`run_hier_many` dispatches through `fl.sim._dispatch_group` and
     solves Γ with `solve_pairs_fused`: their spans are recorded there too,
